@@ -39,6 +39,7 @@ from repro.configs.base import get_config
 from repro.core import autograd as ag
 from repro.core.autograd import functions as F
 from repro.core.tensor import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.runtime import ServingPolicy
 from repro.serving import Request, ServeEngine, beam_decode
@@ -181,6 +182,7 @@ def main():
                     help="smaller beam section (CI smoke)")
     ap.add_argument("--out", default=None, help="write JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rows = run()
     for name, val, derived in rows:

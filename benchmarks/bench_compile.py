@@ -22,7 +22,9 @@ verification must be effectively free.
 
 ``--quick`` shrinks repetitions and skips the jit section (the XLA
 compile dominates CI minutes and says nothing about analysis cost);
-``--out PATH`` writes a JSON artifact.
+``--out PATH`` writes a JSON artifact.  Unlike the other benchmarks it
+leaves the persistent compilation cache (``repro.launch.compile_cache``)
+off, since compilation is what it measures.
 """
 
 from __future__ import annotations

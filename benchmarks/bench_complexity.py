@@ -10,6 +10,7 @@ from __future__ import annotations
 from pathlib import Path
 
 from repro.core.tensor import TensorBackend
+from repro.launch.compile_cache import enable_compile_cache
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -57,5 +58,6 @@ def run() -> list[tuple[str, float, str]]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for name, val, derived in run():
         print(f"{name},{val:.0f},{derived}")

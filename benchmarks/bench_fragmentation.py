@@ -23,6 +23,7 @@ from repro.core.autograd import Variable
 from repro.core.memory import (BumpMemoryManager, CachingMemoryManager,
                                telemetry)
 from repro.core.tensor import ops, use_backend
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _record_mlp_trace():
@@ -112,5 +113,6 @@ def run() -> list[tuple[str, float, str]]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for name, val, derived in run():
         print(f"{name},{val:.4f},{derived}")

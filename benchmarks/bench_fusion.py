@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import bench_artifact
 import repro
 from repro.core.tensor import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import CompilerPolicy
 
 
@@ -237,6 +238,7 @@ def main() -> None:
     ap.add_argument("--n-ops", type=int, default=None)
     ap.add_argument("--iters", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_ops = args.n_ops or 16
     iters = args.iters or (5 if args.quick else 20)

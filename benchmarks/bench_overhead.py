@@ -23,6 +23,7 @@ from repro import obs
 from repro.core import autograd as ag
 from repro.core import nn
 from repro.core.autograd import functions as F
+from repro.launch.compile_cache import enable_compile_cache
 
 ITERS = 100
 
@@ -327,6 +328,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--out", metavar="PATH", default=None,
                     help="write a JSON artifact to PATH")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     result: dict = {}
     if not args.quick:

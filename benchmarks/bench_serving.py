@@ -38,6 +38,7 @@ import repro
 from repro import obs
 from repro.configs.base import get_config
 from repro.core.tensor import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.runtime import ServingPolicy
 from repro.serving import FixedProposer, Request, Router, ServeEngine
@@ -480,6 +481,7 @@ def main():
                     help="run an observability-on scenario and write a "
                     "Perfetto-loadable Chrome trace JSON to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
 
     overrides = {}
     if args.quick:

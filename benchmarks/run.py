@@ -23,6 +23,9 @@ def main() -> None:
         ("Case 5.2.2 (fragmentation)", bench_fragmentation.run, 1.0),
         ("Fusion (deferred backend)", bench_fusion.run, 1e6),
     ]
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     failures = 0
     for title, fn, scale in suites:
         print(f"# {title}")
@@ -50,6 +53,7 @@ def main() -> None:
                       f"MFU@bound={r['roofline_fraction']*100:.1f}% "
                       f"dominant={r['dominant']}")
     except Exception:  # noqa: BLE001
+        failures += 1
         traceback.print_exc()
 
     if failures:

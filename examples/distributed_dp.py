@@ -2,14 +2,17 @@
 (paper §4.1.3 / A.4.1): shard_map training step with bucketed, optionally
 int8-compressed (error-feedback) gradient all-reduce.
 
-Spawns itself with 8 fake host devices when run on 1 device.
+Runs in one process over the default backend's devices.  Before JAX is
+imported it asks for 8 host (CPU) devices, so a CPU-only run still has an
+8-way data axis; on an accelerator host the accelerator's devices are the
+default and the host devices go unused.
 
 Run:  PYTHONPATH=src python examples/distributed_dp.py
 """
 
 import os
-import subprocess
-import sys
+
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=8"
 
 
 def _worker():
@@ -19,7 +22,7 @@ def _worker():
     from repro.core.distributed import ShardMapBackend, init_distributed
     from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh((8,), ("data",))
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     dist = init_distributed(ShardMapBackend("data"))
 
     with repro.session(mesh=mesh, batch_axes=("data",),
@@ -33,7 +36,6 @@ def _train(mesh, dist):
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
     from repro.core.distributed import GradientSynchronizer, GradSyncConfig
 
     d, classes = 32, 4
@@ -62,7 +64,7 @@ def _train(mesh, dist):
             return new_p, ef, jax.lax.pmean(loss, "data")
 
         ef0 = sync.init_state(params)
-        sharded_step = jax.jit(shard_map(
+        sharded_step = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(), jax.tree.map(lambda _: P(), ef0), P("data"),
                       P("data")),
@@ -84,21 +86,11 @@ def _train(mesh, dist):
 
 
 def main():
-    import jax
-
-    if len(jax.devices()) >= 8:
-        _worker()
-        return
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = "src"
-    env["REPRO_DP_WORKER"] = "1"
-    r = subprocess.run([sys.executable, __file__], env=env)
-    sys.exit(r.returncode)
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {HOST_DEVICES_FLAG}".strip()
+    _worker()
 
 
 if __name__ == "__main__":
-    if os.environ.get("REPRO_DP_WORKER"):
-        _worker()
-    else:
-        main()
+    main()

@@ -104,6 +104,7 @@ def check_clusters(graph: "Graph", policy: "AnalysisPolicy | None" = None,
     """Verify the fusion partition, cluster-kind consistency, and
     per-cluster VMEM budgets."""
     from repro.compiler.graph import REDUCTION_OPS
+    from repro.kernels.cluster import row_block
     from repro.runtime.policies import AnalysisPolicy
 
     policy = policy or AnalysisPolicy()
@@ -167,7 +168,12 @@ def check_clusters(graph: "Graph", policy: "AnalysisPolicy | None" = None,
         if all(u in graph.nodes for u in cl.node_ids + cl.inputs + cl.outputs):
             peak = _cluster_peak_bytes(graph, cl.node_ids, cl.inputs,
                                        cl.outputs)
-            if peak > policy.vmem_limit_bytes:
+            # a row-griddable cluster streams blocks sized to the budget,
+            # so only its whole-array residency may exceed it
+            if peak > policy.vmem_limit_bytes and row_block(
+                    [graph.nodes[u] for u in cl.node_ids],
+                    [graph.nodes[u] for u in cl.inputs], len(cl.outputs),
+                    policy.vmem_limit_bytes) is None:
                 report.add("vmem.over-budget", Severity.WARNING,
                            f"estimated peak residency {peak} B exceeds the "
                            f"per-cluster VMEM budget "

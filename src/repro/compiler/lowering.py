@@ -23,6 +23,7 @@ from typing import Any, Callable
 import jax
 
 from repro.kernels import cluster as cluster_kernels
+from repro.runtime import current_session
 
 from .graph import Graph
 from .passes import PassStats
@@ -189,6 +190,7 @@ def _lower(graph: Graph, policy: Any, report: list[PassStats] | None = None,
            plan: tuple | None = None) -> Executable:
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    vmem_limit = current_session().analysis.vmem_limit_bytes
     consts = {uid: graph.nodes[uid].value for uid in graph.order
               if graph.nodes[uid].op == "const"}
 
@@ -267,9 +269,10 @@ def _lower(graph: Graph, policy: Any, report: list[PassStats] | None = None,
                 members, ins, outs, cl.meta, interpret=interpret)
             kind = "pallas"
         elif cluster_kernels.pallas_supported(
-                members, ins, on_tpu=not interpret):
+                members, ins, len(outs), on_tpu=not interpret,
+                vmem_limit=vmem_limit):
             fn = cluster_kernels.build_cluster_kernel(
-                members, ins, outs, interpret=interpret)
+                members, ins, outs, vmem_limit, interpret=interpret)
             kind = "pallas"
         else:
             fn = cluster_kernels.build_jit_cluster(members, ins, outs)

@@ -128,9 +128,7 @@ class ShardMapBackend(DistributedInterface):
         return jax.lax.axis_index(self.axis_name)
 
     def getWorldSize(self):
-        if hasattr(jax.lax, "axis_size"):
-            return jax.lax.axis_size(self.axis_name)
-        return jax.lax.psum(1, self.axis_name)  # pre-0.6 jax
+        return jax.lax.axis_size(self.axis_name)
 
     def allReduce(self, x, scale: float = 1.0, async_op: bool = False):
         def run(v):

@@ -137,8 +137,10 @@ class Adam(Optimizer):
         self.weight_decay = weight_decay
 
     def _init_leaf(self, p):
-        return {"m": self._cast(jnp.zeros_like(p)),
-                "v": self._cast(jnp.zeros_like(p))}
+        # moments start in the float32 that _update_leaf returns them in,
+        # so a jitted step sees one state dtype from its first call on
+        return {"m": self._cast(jnp.zeros_like(p, jnp.float32)),
+                "v": self._cast(jnp.zeros_like(p, jnp.float32))}
 
     def _update_leaf(self, p, g, s, lr, count):
         lr = self.lr if lr is None else lr

@@ -9,9 +9,13 @@ per cluster:
   from VMEM, running the region's ops on register values, and writing each
   external output once.  That is the ArrayFire-JIT payoff (paper §4.1.1,
   Fig. 2) made concrete: N dispatches collapse into a single kernel whose
-  arithmetic intensity grows with the cluster.  Reduction-tailed regions
-  (softmax denominators, mean chains) ride the same whole-array kernel —
-  the body replays the ops' own closures, so mixed shapes are exact.
+  arithmetic intensity grows with the cluster.  A purely elementwise
+  cluster over one common shape streams row blocks through a 1-D grid
+  sized to the VMEM budget (:func:`row_block`), so it runs at any array
+  size.  Reduction-tailed regions (softmax denominators, mean chains)
+  keep one whole-array step — the body replays the ops' own closures, so
+  mixed shapes are exact — which only the interpreter runs; on TPU they
+  take the jit fallback.
 * ``epilogue`` — a 2-D matmul plus its consumer cone; the synthesized
   epilogue body is folded into the tiled matmul kernel's store step
   (:func:`repro.kernels.matmul.matmul_epilogue`).
@@ -38,36 +42,96 @@ from jax.experimental import pallas as pl
 _TPU_DTYPES = (jnp.float32, jnp.bfloat16)
 
 
+def _erf_rational(x):
+    """erf by Eigen's single-precision rational approximation (odd
+    degree-13 numerator over even degree-8 denominator, input clamped to
+    [-4, 4] where erf is +-1 in f32).  Mosaic has no lowering for
+    ``lax.erf``; this one uses only multiply, add and divide."""
+    alpha = (-2.72614225801306e-10, 2.77068142495902e-08,
+             -2.10102402082508e-06, -5.69250639462346e-05,
+             -7.34990630326855e-04, -2.95459980854025e-03,
+             -1.60960333262415e-02)
+    beta = (-1.45660718464996e-05, -2.13374055278905e-04,
+            -1.68282697438203e-03, -7.37332916720468e-03,
+            -1.42647390514189e-02)
+    x32 = jnp.clip(x.astype(jnp.float32), -4.0, 4.0)
+    x2 = x32 * x32
+    p = jnp.full_like(x2, alpha[0])
+    for a in alpha[1:]:
+        p = p * x2 + a
+    q = jnp.full_like(x2, beta[0])
+    for b in beta[1:]:
+        q = q * x2 + b
+    return (x32 * p / q).astype(x.dtype)
+
+
 def make_body(nodes: Sequence[Any], input_ids: Sequence[int],
-              output_ids: Sequence[int]) -> Callable:
+              output_ids: Sequence[int], mosaic: bool = False) -> Callable:
     """Synthesize the cluster's straight-line body: values in, values out.
 
     Shared by every lowering: the Pallas kernel wraps it with ref
-    reads/writes; the jit fallback compiles it directly.
+    reads/writes; the jit fallback compiles it directly.  ``mosaic``
+    marks a body compiled to a TPU kernel, where ``erf`` becomes
+    :func:`_erf_rational`.
     """
     nodes = tuple(nodes)
     input_ids = tuple(input_ids)
     output_ids = tuple(output_ids)
+    fns = [_erf_rational if mosaic and n.op == "erf" else n.fn
+           for n in nodes]
 
     def body(*vals):
         env = dict(zip(input_ids, vals))
-        for n in nodes:
-            env[n.uid] = n.fn(*[env[d] for d in n.inputs])
+        for n, fn in zip(nodes, fns):
+            env[n.uid] = fn(*[env[d] for d in n.inputs])
         return tuple(env[o] for o in output_ids)
 
     body.__name__ = f"cluster_{'_'.join(n.op for n in nodes[:4])}"
     return body
 
 
+def row_block(nodes: Sequence[Any], input_nodes: Sequence[Any],
+              n_outputs: int, vmem_limit: int) -> int | None:
+    """Rows per grid step for a row-gridded cluster kernel, or None.
+
+    Applies to clusters of rank-agnostic elementwise ops over one common
+    shape of rank >= 2: the kernel views every operand as ``[rows, cols]``
+    (leading dims flattened) and streams ``(block, cols)`` tiles.  The
+    block is the largest divisor of ``rows`` that is a multiple of 8 (or
+    ``rows`` itself) whose double-buffered tiles of every input and output
+    fit ``vmem_limit``; None when the cluster is not of that form or not
+    even 8 rows fit.
+    """
+    from repro.compiler.graph import ELEMENTWISE_OPS
+
+    shapes = {tuple(n.shape) for n in list(nodes) + list(input_nodes)}
+    if len(shapes) != 1 or any(n.op not in ELEMENTWISE_OPS for n in nodes):
+        return None
+    (shape,) = shapes
+    if len(shape) < 2:
+        return None
+    rows, cols = math.prod(shape[:-1]), shape[-1]
+    itemsize = max(jnp.dtype(n.dtype).itemsize
+                   for n in list(nodes) + list(input_nodes))
+    fit = vmem_limit // (2 * (len(input_nodes) + n_outputs) * cols * itemsize)
+    if rows <= fit:
+        return rows
+    for block in range(fit - fit % 8, 7, -8):
+        if rows % block == 0:
+            return block
+    return None
+
+
 def pallas_supported(nodes: Sequence[Any], input_nodes: Sequence[Any],
-                     on_tpu: bool) -> bool:
+                     n_outputs: int, on_tpu: bool, vmem_limit: int) -> bool:
     """Can this elementwise/reduction cluster become one ``pallas_call``?
 
-    Off-TPU the whole-array kernel replays the body under interpret mode,
-    which is exact for any mix of shapes (implicit broadcasting,
-    keepdims/keepdims-less reductions); only rank-0 values are kept on the
-    jit path.  On TPU the tiling is conservative: one common VPU-tileable
-    shape and supported dtypes.
+    Off-TPU the kernel runs under interpret mode, which is exact for any
+    mix of shapes (implicit broadcasting, keepdims/keepdims-less
+    reductions); only rank-0 values are kept on the jit path.  On TPU the
+    cluster must be row-griddable (:func:`row_block`) over a VPU-tileable
+    shape in supported dtypes; everything else, reduction tails included,
+    takes the counted jit fallback.
     """
     shapes = {tuple(n.shape) for n in nodes}
     shapes |= {tuple(n.shape) for n in input_nodes}
@@ -75,25 +139,27 @@ def pallas_supported(nodes: Sequence[Any], input_nodes: Sequence[Any],
         return False
     if not on_tpu:
         return True
-    if len(shapes) != 1:
+    if row_block(nodes, input_nodes, n_outputs, vmem_limit) is None:
         return False
     (shape,) = shapes
-    if len(shape) < 2 or shape[-1] % 128 != 0 or shape[-2] % 8 != 0:
+    if shape[-1] % 128 != 0 or shape[-2] % 8 != 0:
         return False
     dtypes = {jnp.dtype(n.dtype) for n in list(nodes) + list(input_nodes)}
     return all(d in _TPU_DTYPES for d in dtypes)
 
 
 def build_cluster_kernel(nodes: Sequence[Any], input_nodes: Sequence[Any],
-                         output_nodes: Sequence[Any],
+                         output_nodes: Sequence[Any], vmem_limit: int,
                          interpret: bool = True) -> Callable:
     """One ``pallas_call`` for the whole cluster.
 
     Returns ``fn(*input_arrays) -> tuple(output_arrays)``; the kernel body
-    is generated from the cluster's op list (see :func:`make_body`).
+    is generated from the cluster's op list (see :func:`make_body`).  It
+    is row-gridded where :func:`row_block` finds a block, and one
+    whole-array step otherwise.
     """
     body = make_body(nodes, [n.uid for n in input_nodes],
-                     [n.uid for n in output_nodes])
+                     [n.uid for n in output_nodes], mosaic=not interpret)
     n_in = len(input_nodes)
 
     def kernel(*refs):
@@ -102,13 +168,30 @@ def build_cluster_kernel(nodes: Sequence[Any], input_nodes: Sequence[Any],
         for r, v in zip(refs[n_in:], outs):
             r[...] = v
 
-    out_shape = [jax.ShapeDtypeStruct(tuple(n.shape), n.dtype)
-                 for n in output_nodes]
-    call = pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret)
+    block = row_block(nodes, input_nodes, len(output_nodes), vmem_limit)
+    if block is None:
+        out_shape = [jax.ShapeDtypeStruct(tuple(n.shape), n.dtype)
+                     for n in output_nodes]
+        call = pl.pallas_call(kernel, out_shape=out_shape,
+                              interpret=interpret)
 
-    def run(*vals):
-        out = call(*vals)
-        return tuple(out)
+        def run(*vals):
+            return tuple(call(*vals))
+    else:
+        shape = tuple(output_nodes[0].shape)
+        cols = shape[-1]
+        rows = math.prod(shape[:-1])
+        spec = pl.BlockSpec((block, cols), lambda i: (i, 0))
+        call = pl.pallas_call(
+            kernel, grid=(rows // block,), in_specs=[spec] * n_in,
+            out_specs=[spec] * len(output_nodes),
+            out_shape=[jax.ShapeDtypeStruct((rows, cols), n.dtype)
+                       for n in output_nodes],
+            interpret=interpret)
+
+        def run(*vals):
+            outs = call(*[v.reshape(rows, cols) for v in vals])
+            return tuple(o.reshape(shape) for o in outs)
 
     run.__name__ = f"pallas_{body.__name__}"
     return run
@@ -226,7 +309,7 @@ def build_epilogue_cluster(nodes: Sequence[Any], input_nodes: Sequence[Any],
     mm_uid = meta["matmul"]
     epi_members = [n for n in nodes if n.uid != mm_uid]
     body = make_body(epi_members, [mm_uid, *meta["epi_ext"]],
-                     [output_nodes[0].uid])
+                     [output_nodes[0].uid], mosaic=not interpret)
     pos = {n.uid: i for i, n in enumerate(input_nodes)}
     by_uid = {n.uid: n for n in input_nodes}
     lhs_i, rhs_i = pos[meta["lhs"]], pos[meta["rhs"]]

@@ -23,7 +23,7 @@ NEG_INF = -2.0 ** 30
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, scale: float, n_kb: int, per_row: bool):
+                   acc_scr, *, scale: float, n_kb: int):
     kb = pl.program_id(1)
 
     @pl.when(kb == 0)
@@ -34,15 +34,15 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
 
     q = q_ref[0]                                    # [1, d] row
     k = k_ref[0]                                    # [bk, d]
-    valid = valid_ref[0] if per_row else valid_ref[...]  # [bk]
+    valid = valid_ref[...].reshape(1, -1) != 0      # [1, bk]
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale  # [1, bk]
-    scores = jnp.where(valid[None, :], scores, NEG_INF)
+    scores = jnp.where(valid, scores, NEG_INF)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid[None, :], jnp.exp(scores - m_new), 0.0)
+    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
     l_scr[...] = l_scr[...] * corr + p.sum(-1, keepdims=True)
     acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
         p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
@@ -135,7 +135,13 @@ def flash_decode(q, k, v, valid, *, scale: float | None = None,
                  bk: int = 512, interpret: bool = False):
     """q: [N, D]; k, v: [N, S, D]; valid: [S] bool shared across rows, or
     [N, S] per-row (paged/continuous-batching caches where every slot
-    sits at its own depth) -> [N, D]."""
+    sits at its own depth) -> [N, D].
+
+    The mask streams as int32 with a unit dimension in front of ``S``, so
+    its block's last two dims are (full 1, ``bk``): Mosaic's tiling rule
+    for the sublane dimension, and bool's own VMEM layout, rule out a
+    ``(1, bk)`` block of an ``[N, S]`` bool array.
+    """
     n, d = q.shape
     s = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -143,12 +149,14 @@ def flash_decode(q, k, v, valid, *, scale: float | None = None,
     assert s % bk == 0, (s, bk)
     n_kb = s // bk
     grid = (n, n_kb)
-    per_row = valid.ndim == 2
-    valid_spec = (pl.BlockSpec((1, bk), lambda i, j: (i, j)) if per_row
-                  else pl.BlockSpec((bk,), lambda i, j: (j,)))
+    if valid.ndim == 2:
+        valid = valid.astype(jnp.int32)[:, None, :]          # [N, 1, S]
+        valid_spec = pl.BlockSpec((1, 1, bk), lambda i, j: (i, 0, j))
+    else:
+        valid = valid.astype(jnp.int32)[None, :]             # [1, S]
+        valid_spec = pl.BlockSpec((1, bk), lambda i, j: (0, j))
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, n_kb=n_kb,
-                          per_row=per_row),
+        functools.partial(_decode_kernel, scale=scale, n_kb=n_kb),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, d), lambda i, j: (i, 0, 0)),

@@ -127,14 +127,10 @@ def plan_epilogue(*, m: int, k: int, n: int,
     return bm, bn, bk
 
 
-def _bcast_spec(s: tuple[int, ...], m: int, n: int, bm: int, bn: int
+def _bcast_spec(s: tuple[int, int], m: int, n: int, bm: int, bn: int
                 ) -> pl.BlockSpec:
-    """BlockSpec for an epilogue operand: tiled along the dims it shares
+    """BlockSpec for a 2-D epilogue operand: tiled along the dims it shares
     with the (m, n) output, pinned to block 0 along broadcast dims."""
-    if len(s) == 1:
-        if s[0] == n:
-            return pl.BlockSpec((bn,), lambda i, j, kk: (j,))
-        return pl.BlockSpec((1,), lambda i, j, kk: (0,))
     rtile, ctile = s[0] == m, s[1] == n
     blk = (bm if rtile else 1, bn if ctile else 1)
 
@@ -182,12 +178,16 @@ def matmul_epilogue(body: Callable, *, m: int, k: int, n: int,
     """
     n_k = k // bk
     grid = (m // bm, n // bn, n_k)
+    # rank-1 operands map to columns and travel as (1, len) rows: a rank-1
+    # block must cover the whole array or a whole dtype-dependent tile on
+    # TPU, while a (1, bn) block is legal for any dtype
+    shapes2 = [(1,) * (2 - len(s)) + tuple(s) for s in extra_shapes]
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
     ]
-    for s in extra_shapes:
-        in_specs.append(_bcast_spec(tuple(s), m, n, bm, bn))
+    for s2 in shapes2:
+        in_specs.append(_bcast_spec(s2, m, n, bm, bn))
     call = pl.pallas_call(
         functools.partial(_epilogue_kernel, body=body,
                           n_extra=len(extra_shapes), n_k=n_k,
@@ -199,4 +199,8 @@ def matmul_epilogue(body: Callable, *, m: int, k: int, n: int,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )
-    return jax.jit(call)
+
+    def run(x, y, *extras):
+        return call(x, y, *[e.reshape(s2) for e, s2 in zip(extras, shapes2)])
+
+    return jax.jit(run)

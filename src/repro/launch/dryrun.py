@@ -201,9 +201,8 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
                       "temp_size_in_bytes", "generated_code_size_in_bytes",
                       "alias_size_in_bytes")
             if hasattr(mem, k)}
-        from repro.core.compat import cost_analysis
 
-        cost = cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         rec["cost"] = {k: float(v) for k, v in cost.items()
                        if isinstance(v, (int, float))
                        and k in ("flops", "bytes accessed",

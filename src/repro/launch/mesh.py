@@ -8,6 +8,7 @@ set XLA_FLAGS before first jax init while tests see the real 1-CPU world.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) — the roofline denominators.
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -16,13 +17,8 @@ ICI_BW = 50e9                     # B/s per link
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: AxisType (explicit-sharding era)
-    when available, plain Auto meshes on older releases."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) sharding mode."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,12 +27,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (CI / examples)."""
-    n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, max(1, n // data))
-    return make_mesh((data, model), ("data", "model"))
+def make_host_mesh():
+    """Data-parallel (data, model) mesh over every device present:
+    ``data`` is the device count and ``model`` is 1."""
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def mesh_chips(mesh) -> int:

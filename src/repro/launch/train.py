@@ -3,8 +3,11 @@
   PYTHONPATH=src python -m repro.launch.train --arch gemma3-27b --reduced \
       --steps 50 --batch 8 --seq 128 --ckpt /tmp/ckpt
 
-Full configs target the production mesh (real TPU pods); ``--reduced``
-runs the same code path end-to-end on the host mesh.
+Training runs data-parallel over every device present (the host mesh), or
+over the production pod mesh with ``--production-mesh``.  ``--reduced``
+runs the same code path end-to-end on the family's smoke-size config.
+:func:`run` is the launcher without argument parsing, for callers in the
+same process.
 """
 
 from __future__ import annotations
@@ -14,12 +17,14 @@ import argparse
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 import repro
-from repro.configs.base import get_config
+from repro.configs.base import ModelConfig, get_config
 from repro.core.data import (BatchDataset, PackedLMDataset, PrefetchDataset,
                              ShuffleDataset, synthetic_corpus)
 from repro.core.optim import AdamW
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build_model
 from repro.sharding.rules import make_rules
@@ -64,8 +69,46 @@ def make_batches(cfg, batch_size: int, seq: int, steps: int, seed: int = 0):
         ds.reshuffle(epoch)
 
 
-def main():
-    ap = argparse.ArgumentParser()
+def run(cfg: ModelConfig, *, steps: int = 100, batch: int = 8,
+        seq: int = 128, lr: float = 1e-3, ckpt: str | None = None,
+        production_mesh: bool = False, seed: int = 0) -> list[dict]:
+    """Train ``cfg`` from seeded random weights on the synthetic corpus;
+    returns the per-step history (``step``, ``loss``, ``sec``)."""
+    model = build_model(cfg)
+    mesh = make_production_mesh() if production_mesh else make_host_mesh()
+    print(f"[train] arch={cfg.name} devices={len(jax.devices())} "
+          f"mesh={dict(mesh.shape)}")
+
+    rules = make_rules("baseline")
+    optimizer = AdamW(lr=lr)
+    # params and optimizer state enter the step placed as it returns them,
+    # so step 1 reuses the program step 0 compiled
+    p_sh = rules.tree_shardings(model.abstract_params(), mesh)
+    o_sh = jax.tree.map(optimizer.state_sharding_like, p_sh,
+                        is_leaf=lambda x: isinstance(x, NamedSharding))
+    params = jax.jit(model.init, out_shardings=p_sh)(
+        jax.random.PRNGKey(seed))
+    n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
+    print(f"[train] params: {n_params/1e6:.2f}M")
+
+    tcfg = TrainConfig(steps=steps, base_lr=lr, checkpoint_dir=ckpt,
+                       warmup=max(2, steps // 20))
+    batches = make_batches(cfg, batch, seq, steps, seed=seed)
+    with repro.session(mesh=mesh, sharding_rules=rules,
+                       tag=f"train:{cfg.name}"):
+        params, history = train(
+            model, params, batches, tcfg, optimizer=optimizer,
+            jit_kwargs={"out_shardings": (p_sh, o_sh, None)})
+    first = np.mean([h["loss"] for h in history[:5]])
+    last = np.mean([h["loss"] for h in history[-5:]])
+    print(f"[train] loss {first:.4f} -> {last:.4f} over {len(history)} steps")
+    return history
+
+
+def main(argv: list[str] | None = None) -> list[dict]:
+    ap = argparse.ArgumentParser(
+        description="Train one architecture data-parallel over every "
+                    "device present (or the production pod mesh).")
     ap.add_argument("--arch", default="codeqwen1.5-7b")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--steps", type=int, default=100)
@@ -73,33 +116,13 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt", default=None)
-    ap.add_argument("--production-mesh", action="store_true")
-    args = ap.parse_args()
-
-    cfg = get_config(args.arch, reduced=args.reduced)
-    model = build_model(cfg)
-    mesh = (make_production_mesh() if args.production_mesh
-            else make_host_mesh())
-    print(f"[train] arch={cfg.name} devices={len(jax.devices())} "
-          f"mesh={dict(mesh.shape)}")
-
-    params = model.init(jax.random.PRNGKey(0))
-    n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
-    print(f"[train] params: {n_params/1e6:.2f}M")
-
-    rules = make_rules("baseline")
-    tcfg = TrainConfig(steps=args.steps, base_lr=args.lr,
-                       checkpoint_dir=args.ckpt,
-                       warmup=max(2, args.steps // 20))
-    batches = make_batches(cfg, args.batch, args.seq, args.steps)
-    with repro.session(mesh=mesh, sharding_rules=rules,
-                       tag=f"train:{cfg.name}"):
-        params, history = train(model, params, batches, tcfg,
-                                optimizer=AdamW(lr=args.lr))
-    first = np.mean([h["loss"] for h in history[:5]])
-    last = np.mean([h["loss"] for h in history[-5:]])
-    print(f"[train] loss {first:.4f} -> {last:.4f} over {len(history)} steps")
-    return history
+    ap.add_argument("--production-mesh", action="store_true",
+                    help="the 16x16 pod mesh instead of the host's devices")
+    args = ap.parse_args(argv)
+    enable_compile_cache()
+    return run(get_config(args.arch, reduced=args.reduced),
+               steps=args.steps, batch=args.batch, seq=args.seq, lr=args.lr,
+               ckpt=args.ckpt, production_mesh=args.production_mesh)
 
 
 if __name__ == "__main__":

@@ -217,9 +217,7 @@ def _moe_a2a(p, x2d, m, cfg, mesh, token_axes, expert_axis="model"):
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
-
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(tok_spec, None), P(None, None),
                   P(expert_axis, None, None), P(expert_axis, None, None),

@@ -51,10 +51,8 @@ def make_flash_decode_attend(mesh: Mesh, *, seq_axes: Sequence[str],
             b, kvh, g, dv = out.shape
             return out.reshape(b, kvh * g, dv).astype(q_l.dtype)
 
-        from repro.core.compat import shard_map
-
         valid_spec = P(bspec, sspec) if valid.ndim == 2 else P(sspec)
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(bspec, None, None),
                       P(bspec, sspec, None, None),

@@ -68,10 +68,8 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stage_params,
             jnp.where(stage_id == n_stages - 1, outputs, 0.0), axis)
         return outputs
 
-    from repro.core.compat import shard_map
-
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(spec_p, P()), out_specs=P(),
         check_vma=False,

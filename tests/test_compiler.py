@@ -416,3 +416,36 @@ def test_selfcheck_default_pipeline_clean():
     problems = selfcheck.run_corpus(
         pipelines=(("cse", "fold", "dce", "fuse"),))
     assert problems == []
+
+
+@pytest.mark.parametrize("vmem_limit,block", [(16 << 20, 64), (64 << 10, 16),
+                                              (1, None)])
+def test_elementwise_cluster_row_block_follows_the_vmem_budget(vmem_limit,
+                                                               block):
+    """64 x 256 f32 with one input and one output, both double-buffered,
+    takes 4 KiB per row: 16 MiB holds all 64 rows, 64 KiB holds 16, and
+    one byte none (one whole-array step here, the jit fallback on TPU).
+    The grid changes nothing in the values: compiled == eager bitwise."""
+    from repro.kernels import cluster
+
+    @repro.compile
+    def f(x):
+        return ops.tanh(ops.mul(ops.sin(x), x))
+
+    x = jax.random.normal(jax.random.PRNGKey(3), (64, 256), jnp.float32)
+    with repro.session(analysis={"vmem_limit_bytes": vmem_limit}):
+        got = f(x)
+    want = jnp.tanh(jnp.sin(x) * x)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    lb = LazyBackend()
+    with repro.session(backend=lb):
+        out = f.fn(lb._lift(x))
+    g, _ = trace([out])
+    PassManager.from_policy(CompilerPolicy(pipeline=("fuse",))).run(g)
+    (cl,) = g.clusters
+    nodes = [g.nodes[u] for u in cl.node_ids]
+    ins = [g.nodes[u] for u in cl.inputs]
+    assert cluster.row_block(nodes, ins, 1, vmem_limit) == block
+    assert cluster.pallas_supported(nodes, ins, 1, on_tpu=True,
+                                    vmem_limit=vmem_limit) == (
+        block is not None)

@@ -80,11 +80,9 @@ _SUBPROC_COLLECTIVES = textwrap.dedent("""
                 d.allGather(local),
                 d.reduceScatter(d.allGather(local)))
 
-    from repro.core.compat import shard_map
-    out = shard_map(body, mesh=mesh, in_specs=P("data"),
-                    out_specs=(P("data"), P(("data",), None) if False
-                               else P("data"), P("data")),
-                    check_vma=False)(x)
+    out = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                        out_specs=(P("data"), P("data"), P("data")),
+                        check_vma=False)(x)
     ar, ag, rs = out
     res = {
       "ar": np.asarray(ar).tolist(),

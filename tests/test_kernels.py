@@ -216,3 +216,17 @@ def test_moe_gmm_sweep(e, c, d, f, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(R.moe_gmm(h, w), np.float32),
                                **_tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_erf_rational_matches_lax_erf(dtype):
+    """Mosaic kernel bodies replace ``lax.erf`` with a rational
+    approximation; it must agree to f32 rounding over the whole line."""
+    from repro.kernels.cluster import _erf_rational
+
+    x = jnp.linspace(-6.0, 6.0, 20001, dtype=jnp.float32).astype(dtype)
+    got = _erf_rational(x)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(jax.lax.erf(x), np.float32),
+                               atol=1e-6 if dtype == jnp.float32 else 8e-3)

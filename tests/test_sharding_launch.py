@@ -60,8 +60,7 @@ def test_cellplan_lowers_on_tiny_mesh():
     fn, args, in_sh, out_sh, donate = plan.lowerable()
     compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                        donate_argnums=donate).lower(*args).compile()
-    from repro.core.compat import cost_analysis
-    assert cost_analysis(compiled)["flops"] > 0
+    assert compiled.cost_analysis()["flops"] > 0
 
     shape_d = ShapeSpec("tiny_decode", 32, 4, "decode")
     plan_d = CellPlan(cfg, shape_d, mesh, BASELINE)

@@ -155,6 +155,19 @@ def test_adamw_decoupled_decay():
                                rtol=1e-6)
 
 
+@pytest.mark.parametrize("state_dtype", [None, jnp.bfloat16])
+def test_adam_state_dtype_is_the_same_before_and_after_a_step(state_dtype):
+    """A jitted train step compiles once only if the state it returns has
+    the dtypes of the state it was given (bf16 params, f32 moments)."""
+    p = {"w": jnp.ones((4, 8), jnp.bfloat16)}
+    opt = optim.AdamW(lr=0.1, state_dtype=state_dtype)
+    s0 = opt.init(p)
+    _, s1 = opt.apply_with_count(p, p, s0, 0.1, 1)
+    dtypes = [x.dtype for x in jax.tree.leaves(s0)]
+    assert dtypes == [x.dtype for x in jax.tree.leaves(s1)]
+    assert set(dtypes) == {jnp.dtype(state_dtype or jnp.float32)}
+
+
 def test_clip_by_global_norm():
     grads = {"a": jnp.full((4,), 3.0), "b": jnp.full((4,), 4.0)}
     clipped, norm = optim.clip_by_global_norm(grads, 1.0)
