@@ -318,6 +318,74 @@ def run_obs_overhead(reps: int = 3) -> dict:
             "on_vs_off": round(on_vs_off, 4)}
 
 
+def _span_step(tracer, i: int) -> None:
+    """The spans of one plain decode step, nested as the engine nests
+    them, around no work."""
+    span = obs.span
+    with span("serve.step", "serving", tracer, step=i, active=16,
+              waiting=0):
+        with span("serve.admit", "serving", tracer) as sp:
+            if sp is not None:
+                sp.attrs["admitted"] = 0
+        with span("serve.ensure_capacity", "serving", tracer):
+            pass
+        with span("serve.decode_step", "serving", tracer, active=16) as sp:
+            if sp is not None:
+                sp.attrs["keys"] = 16 * 2048
+            with span("serve.decode_dispatch", "serving", tracer):
+                pass
+            with span("serve.decode_wait", "serving", tracer):
+                pass
+        with span("serve.emit", "serving", tracer) as sp:
+            if sp is not None:
+                sp.attrs["finished"] = 0
+
+
+def run_span_cost(n: int = 20_000) -> dict:
+    """Microseconds one plain decode step's seven spans add, and one span
+    on its own, with both sinks off, with a profiler session collecting
+    (host events only, as ``bench/`` traces), and with observability on
+    (an empty loop subtracted)."""
+    import tempfile
+
+    from repro.obs import Tracer
+
+    def per_call(fn) -> float:
+        t0 = obs.now()
+        for i in range(n):
+            fn(i)
+        return (obs.now() - t0) / n * 1e6
+
+    def one(tracer):
+        def f(i):
+            with obs.span("serve.emit", "serving", tracer, finished=0):
+                pass
+        return f
+
+    def timed(tracer) -> dict:
+        empty = per_call(lambda i: None)
+        return {"span_us": per_call(one(tracer)) - empty,
+                "step_us": per_call(
+                    lambda i: _span_step(tracer, i)) - empty}
+
+    out = {"off": timed(None)}
+    # as a traced benchmark run collects: host events, no Python tracer
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            out["profiler"] = timed(None)
+        finally:
+            jax.profiler.stop_trace()
+    out["obs"] = timed(Tracer(max_events=n))
+    for mode, r in out.items():
+        print(f"span_cost_{mode},{r['span_us']:.3f} us per span,"
+              f"{r['step_us']:.3f} us per step (7 spans)")
+    return out
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--quick", action="store_true",
@@ -327,7 +395,13 @@ def main(argv: list[str] | None = None) -> int:
                     help="interleaved reps per obs mode")
     ap.add_argument("--out", metavar="PATH", default=None,
                     help="write a JSON artifact to PATH")
+    ap.add_argument("--spans", action="store_true",
+                    help="only time the spans of a serving step: sinks "
+                    "off, profiler collecting, observability on")
     args = ap.parse_args(argv)
+    if args.spans:
+        run_span_cost()
+        return 0
     enable_compile_cache()
 
     result: dict = {}

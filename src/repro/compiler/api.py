@@ -209,13 +209,9 @@ class CompiledFunction:
             pos = arg_pos.get(cid)
             env[cid] = (jnp.asarray(args[pos]) if pos is not None
                         else captured[cid])
-        if tracer is None:
+        with obs.span("compiler.execute", "compiler", tracer,
+                      fn=self.__name__, dispatches=exe.n_dispatches):
             outs = exe.output_values(exe.run(env))
-        else:
-            with tracer.span("compiler.execute", "compiler",
-                             fn=self.__name__,
-                             dispatches=exe.n_dispatches):
-                outs = exe.output_values(exe.run(env))
         return jax.tree_util.tree_unflatten(treedef, outs)
 
 

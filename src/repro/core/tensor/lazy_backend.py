@@ -30,7 +30,6 @@ study in ``benchmarks/bench_fragmentation.py``.
 from __future__ import annotations
 
 import itertools
-from contextlib import nullcontext as _nullcontext
 from typing import Any, Callable, Sequence
 
 import jax
@@ -187,10 +186,8 @@ class LazyBackend(TensorBackend):
         policy = sess.compiler
         analysis = sess.analysis
         tracer = obs.get_tracer(sess)
-        cm = (tracer.span("compiler.materialize", "compiler",
-                          roots=len(roots))
-              if tracer is not None else _nullcontext())
-        with cm:
+        with obs.span("compiler.materialize", "compiler", tracer,
+                      roots=len(roots)):
             graph, sources = _graph.trace(roots)
             self.ops_fused += sum(1 for uid in graph.order
                                   if graph.nodes[uid].op in _ELEMENTWISE)
@@ -224,12 +221,9 @@ class LazyBackend(TensorBackend):
             self.last_analysis = exe.diagnostics
 
             env = {cid: sources[cid].value for cid in exe.inputs}
-            if tracer is None:
+            with obs.span("compiler.execute", "compiler", tracer,
+                          dispatches=exe.n_dispatches):
                 env = exe.run(env)
-            else:
-                with tracer.span("compiler.execute", "compiler",
-                                 dispatches=exe.n_dispatches):
-                    env = exe.run(env)
 
         # allocation telemetry over surviving logical nodes; uids are the
         # LazyTensor uids so events stay unique across materializations

@@ -16,6 +16,12 @@ the off path is a single attribute check.  Sessions derived from an
 enabled one (nested ``repro.session(...)``) share the same tracer, so
 compiler, serving, and memory events land in one stream.
 
+Spans have a second sink: whenever a JAX profiler session is collecting
+(``jax.profiler.start_trace``), :func:`span` also writes a
+``jax.profiler.TraceAnnotation`` of the same name and attributes, with
+observability on or off, so the profiler's trace can put device idle
+time down to the host work that was running.
+
 Summarize a trace offline::
 
     python -m repro.obs summarize trace.json
@@ -29,7 +35,8 @@ from typing import Any
 from repro.obs.clock import now
 from repro.obs.export import save_trace, to_chrome_trace, validate_chrome_trace
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.trace import Instant, Sample, Span, Tracer
+from repro.obs.trace import (Instant, Sample, Span, SpanScope, Tracer,
+                             profiling)
 
 __all__ = [
     "now",
@@ -50,6 +57,11 @@ __all__ = [
 ]
 
 
+#: default of :func:`span`'s ``tracer``: the ambient session's tracer
+AMBIENT: Any = object()
+_OFF: nullcontext[None] = nullcontext(None)
+
+
 def get_tracer(sess: Any | None = None) -> Tracer | None:
     """The tracer of ``sess`` (default: the ambient session), or ``None``
     when its observability policy is disabled or absent."""
@@ -63,14 +75,24 @@ def get_tracer(sess: Any | None = None) -> Tracer | None:
     return tracer if isinstance(tracer, Tracer) else None
 
 
-def span(name: str, cat: str = "",
-         **attrs: Any) -> AbstractContextManager[Span | None]:
-    """Context manager recording a span on the ambient session's tracer;
-    a no-op (yielding ``None``) when observability is off."""
-    tracer = get_tracer()
-    if tracer is None:
-        return nullcontext(None)
-    return tracer.span(name, cat, **attrs)
+def span(name: str, cat: str = "", tracer: Any = AMBIENT, **attrs: Any
+         ) -> AbstractContextManager[Span | SpanScope | None]:
+    """Context manager for one span on both sinks: ``tracer`` (default:
+    the ambient session's, ``None``: none) and, while a profiler session
+    collects, a ``TraceAnnotation`` with the same name and attributes.
+
+    Attribute values are ``int``, ``float``, ``str`` or ``bool``.  The
+    span yields the tracer's :class:`Span` (or, with the profiler alone,
+    the :class:`SpanScope`), whose ``attrs`` may be added to until it
+    ends — both sinks receive them — or ``None`` when neither sink is
+    on.  The off path is one check, so an attribute that costs more
+    than a ``len()`` is set through the yielded object, under ``if sp
+    is not None``."""
+    if tracer is AMBIENT:
+        tracer = get_tracer()
+    if tracer is None and not profiling():
+        return _OFF
+    return SpanScope(tracer, name, cat, attrs)
 
 
 def instant(name: str, cat: str = "", ts: float | None = None,

@@ -9,6 +9,10 @@ thread id and are exported on separate tracks.
 The tracer never allocates past ``max_events`` retained events — beyond
 that, finished events are dropped and counted (``dropped``), keeping
 obs-on cost bounded on long runs.
+
+:class:`SpanScope` records one span into a tracer and, while a JAX
+profiler session collects, into the profiler's trace as a
+``TraceAnnotation`` on the profiler's clock.
 """
 
 from __future__ import annotations
@@ -17,12 +21,19 @@ import itertools
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Iterator
+from types import TracebackType
+from typing import Any, Callable, Iterator
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.clock import now
 from repro.obs.metrics import MetricsRegistry
 
-__all__ = ["Span", "Instant", "Sample", "Tracer"]
+__all__ = ["Span", "Instant", "Sample", "Tracer", "SpanScope", "profiling"]
+
+
+#: whether a JAX profiler session is collecting host events now
+profiling: Callable[[], bool] = TraceAnnotation.is_enabled
 
 
 @dataclass(eq=False)
@@ -173,3 +184,42 @@ class Tracer:
                 "dropped": self.dropped,
                 "threads": len(self.thread_names),
             }
+
+
+class SpanScope:
+    """One span on two independent sinks: ``tracer`` when it is not
+    ``None``, and a ``TraceAnnotation`` when a profiler session collects
+    at entry.  Yields the tracer's :class:`Span`, or without a tracer
+    itself; either way the yielded ``attrs`` may be added to until the
+    span ends, and the profiler receives them all then."""
+
+    __slots__ = ("tracer", "name", "cat", "attrs", "_span", "_note")
+
+    def __init__(self, tracer: Tracer | None, name: str, cat: str,
+                 attrs: dict[str, Any]) -> None:
+        self.tracer = tracer
+        self.name = name
+        self.cat = cat
+        self.attrs = attrs
+        self._span: Span | None = None
+        self._note: TraceAnnotation | None = None
+
+    def __enter__(self) -> Span | SpanScope:
+        if self.tracer is not None:
+            self._span = self.tracer.begin(self.name, self.cat, **self.attrs)
+            self.attrs = self._span.attrs
+        if profiling():
+            self._note = TraceAnnotation(self.name)
+            self._note.__enter__()
+        return self if self._span is None else self._span
+
+    def __exit__(self, typ: type[BaseException] | None,
+                 exc: BaseException | None,
+                 tb: TracebackType | None) -> None:
+        if self._note is not None:
+            if self.attrs:
+                self._note.set_metadata(**self.attrs)
+            self._note.__exit__(typ, exc, tb)
+        if self._span is not None:
+            assert self.tracer is not None
+            self.tracer.finish(self._span)

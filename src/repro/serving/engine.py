@@ -37,7 +37,6 @@ slot-granular engine does not do.
 from __future__ import annotations
 
 import warnings
-from contextlib import nullcontext as _nullcontext
 from dataclasses import dataclass, field
 
 import jax
@@ -231,10 +230,9 @@ class ServeEngine:
         return self.kv.device_table() if self.paged else None
 
     def _span(self, name: str, **attrs):
-        """A tracer span when observability is on; free no-op otherwise."""
-        if self._obs is None:
-            return _nullcontext(None)
-        return self._obs.span(name, "serving", **attrs)
+        """A span on the pinned session's tracer and, while a profiler
+        session collects, on the profiler's trace (``repro.obs.span``)."""
+        return obs.span(name, "serving", self._obs, **attrs)
 
     # -- request lifecycle ---------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -251,32 +249,58 @@ class ServeEngine:
         return len(self.scheduler)
 
     def _admit(self) -> list[tuple[int, Request, list[int], int]]:
+        """Pop waiting requests into the free slots, lowest first
+        (deterministic traces), until the queue or the slots run out or
+        the pool is dry; then prefill the admitted ones together."""
         free = sorted(s for s in range(self.slots) if s not in self.active)
         admitted: list[tuple[int, Request, list[int], int]] = []
-        while free:
-            req = self.scheduler.pop()
-            if req is None:
-                break
-            slot = free.pop(0)                      # ascending: determinism
-            if self._recurrent and (self.active or slot in self._slots_used):
-                raise ValueError(
-                    "SSM-family models carry recurrent state: admitting "
-                    "request %d %s would advance the shared recurrence at "
-                    "the wrong times and silently corrupt decoding; SSM "
-                    "serving supports one request per pristine slot at a "
-                    "time (use batch-level bulk prefill — model.prefill — "
-                    "for concurrent SSM workloads)" % (
-                        req.uid, "mid-flight" if self.active
-                        else f"into recycled slot {slot}"))
-            self._slots_used.add(slot)
-            # a preempted request resumes from prompt + tokens so far
-            eff = req.prompt + req.generated
-            if len(eff) - 1 >= self.max_seq:
-                raise ValueError(
-                    f"request {req.uid} prompt ({len(eff)} tokens) does "
-                    f"not fit max_seq={self.max_seq}; requeueing would "
-                    "spin forever")
-            shared = 0
+        with self._span("serve.admit") as sp:
+            while free:
+                req = self.scheduler.pop()
+                if req is None:
+                    break
+                entry = self._admit_request(req, free.pop(0))
+                if entry is None:
+                    break
+                admitted.append(entry)
+            if sp is not None:
+                sp.attrs["admitted"] = len(admitted)
+        if admitted:
+            if self._chunked:
+                self._prefill_chunked(admitted)
+            else:
+                for slot, _req, eff, _shared in admitted:
+                    self._prefill_per_token(slot, eff)
+        return admitted
+
+    def _admit_request(self, req: Request, slot: int
+                       ) -> tuple[int, Request, list[int], int] | None:
+        """Map ``req``'s KV into ``slot``: the longest cached prefix, the
+        private tail, copy-on-write of a shared block the prefill will
+        diverge into, and registration of its full blocks.  Returns
+        ``(slot, req, effective prompt, shared positions)``, or ``None``
+        when the pool is dry and the request went back to the queue."""
+        if self._recurrent and (self.active or slot in self._slots_used):
+            raise ValueError(
+                "SSM-family models carry recurrent state: admitting "
+                "request %d %s would advance the shared recurrence at "
+                "the wrong times and silently corrupt decoding; SSM "
+                "serving supports one request per pristine slot at a "
+                "time (use batch-level bulk prefill — model.prefill — "
+                "for concurrent SSM workloads)" % (
+                    req.uid, "mid-flight" if self.active
+                    else f"into recycled slot {slot}"))
+        self._slots_used.add(slot)
+        # a preempted request resumes from prompt + tokens so far
+        eff = req.prompt + req.generated
+        if len(eff) - 1 >= self.max_seq:
+            raise ValueError(
+                f"request {req.uid} prompt ({len(eff)} tokens) does "
+                f"not fit max_seq={self.max_seq}; requeueing would "
+                "spin forever")
+        shared = 0
+        with self._span("serve.admit_request", uid=req.uid,
+                        prompt_tokens=len(eff)) as sp:
             if self.paged:
                 if self.kv.blocks_for(len(eff) - 1) > self.kv.usable_blocks:
                     raise OutOfMemory(
@@ -311,26 +335,22 @@ class ServeEngine:
                         self._obs.instant("request.requeue", "serving",
                                           uid=req.uid, reason="admit-oom")
                     self.scheduler.requeue(req)
-                    break
+                    return None
                 if shared:
                     self.shared_admissions += 1
-            req.admit_seq = self._admit_counter
-            self._admit_counter += 1
-            self.active[slot] = req
-            self.slot_pos[slot] = len(eff) - 1
-            self.slot_tok[slot, 0] = eff[-1]
-            if self._obs is not None:
-                self._obs.instant("request.admit", "serving", uid=req.uid,
-                                  slot=slot, admit_seq=req.admit_seq,
-                                  prompt_tokens=len(eff), shared=shared)
-            admitted.append((slot, req, eff, shared))
-        if admitted:
-            if self._chunked:
-                self._prefill_chunked(admitted)
-            else:
-                for slot, _req, eff, _shared in admitted:
-                    self._prefill_per_token(slot, eff)
-        return admitted
+            if sp is not None:
+                sp.attrs["shared"] = shared
+                sp.attrs["queued_ms"] = (obs.now() - req.submit_time) * 1e3
+        req.admit_seq = self._admit_counter
+        self._admit_counter += 1
+        self.active[slot] = req
+        self.slot_pos[slot] = len(eff) - 1
+        self.slot_tok[slot, 0] = eff[-1]
+        if self._obs is not None:
+            self._obs.instant("request.admit", "serving", uid=req.uid,
+                              slot=slot, admit_seq=req.admit_seq,
+                              prompt_tokens=len(eff), shared=shared)
+        return slot, req, eff, shared
 
     def _prefill_chunked(self, admitted) -> None:
         """All newly admitted slots prefill together, one jitted call per
@@ -343,41 +363,56 @@ class ServeEngine:
         bit-identical to the sharing-off path (the rewrites are
         idempotent: identical values at identical positions).  A slot
         whose whole prompt is cached skips prefill entirely.
+
+        ``serve.prefill_chunk`` spans time one call's host dispatch; the
+        call's device time is the ``_prefill_fn`` program on the
+        profiler's trace.
         """
         t = self.policy.prefill_chunk
-        plan = []                            # (slot, eff, c0)
-        for slot, _req, eff, shared in admitted:
-            n = len(eff) - 1
-            if self.prefix_on and shared >= n:
-                self.prefill_tokens_saved += n
-                continue
-            c0 = (min(shared, n) // t) * t if self.prefix_on else 0
-            self.prefill_tokens_saved += c0
-            plan.append((slot, eff, c0))
-        if plan:
-            longest = max(len(eff) - 1 for _s, eff, _c in plan)
-            first = min(c0 for _s, _e, c0 in plan)
-            bt = self._block_table()
-            for c in range(first, longest, t):
-                toks = np.zeros((self.slots, t), np.int32)
-                start = np.zeros(self.slots, np.int32)
-                count = np.zeros(self.slots, np.int32)
-                for slot, eff, c0 in plan:
-                    if c < c0:
-                        continue
-                    seg = eff[:-1][c:c + t]
-                    if not seg:
-                        continue
-                    toks[slot, :len(seg)] = seg
-                    start[slot] = c
-                    count[slot] = len(seg)
-                with self._span("serve.prefill_chunk", chunk_start=c,
-                                chunk=t, slots=len(plan)):
-                    self.cache = self._prefill(self.params, self.cache,
-                                               jnp.asarray(toks),
-                                               jnp.asarray(start),
-                                               jnp.asarray(count), bt)
+        with self._span("serve.prefill") as sp:
+            plan = []                        # (slot, eff, c0)
+            saved = 0
+            for slot, _req, eff, shared in admitted:
+                n = len(eff) - 1
+                if self.prefix_on and shared >= n:
+                    saved += n
+                    continue
+                c0 = (min(shared, n) // t) * t if self.prefix_on else 0
+                saved += c0
+                plan.append((slot, eff, c0))
+            self.prefill_tokens_saved += saved
+            calls = needed = 0
+            if plan:
+                longest = max(len(eff) - 1 for _s, eff, _c in plan)
+                first = min(c0 for _s, _e, c0 in plan)
+                bt = self._block_table()
+                for c in range(first, longest, t):
+                    toks = np.zeros((self.slots, t), np.int32)
+                    start = np.zeros(self.slots, np.int32)
+                    count = np.zeros(self.slots, np.int32)
+                    real = 0                 # prompt tokens in this call
+                    for slot, eff, c0 in plan:
+                        if c < c0:
+                            continue
+                        seg = eff[:-1][c:c + t]
+                        if not seg:
+                            continue
+                        toks[slot, :len(seg)] = seg
+                        start[slot] = c
+                        count[slot] = len(seg)
+                        real += len(seg)
+                    with self._span("serve.prefill_chunk", tokens=real,
+                                    capacity=self.slots * t):
+                        self.cache = self._prefill(self.params, self.cache,
+                                                   jnp.asarray(toks),
+                                                   jnp.asarray(start),
+                                                   jnp.asarray(count), bt)
                     self.prefill_calls += 1
+                    calls += 1
+                    needed += real
+            if sp is not None:
+                sp.attrs.update(calls=calls, tokens_needed=needed,
+                                tokens_saved=saved)
         if self.prefix_on:
             # device content for this round's registrations now exists
             for slot, _req, _eff, _shared in admitted:
@@ -465,11 +500,8 @@ class ServeEngine:
         Plain mode emits one token per slot per step; speculative mode
         runs one draft-propose / wide-verify round emitting 1..k+1
         tokens per slot (token-for-token identical output)."""
-        if self._obs is None:
-            if self.spec_on:
-                return self._spec_step()
-            return self._plain_step()
-        with self._obs.span("serve.step", "serving", step=self.steps):
+        with self._span("serve.step", step=self.steps,
+                        active=len(self.active), waiting=self.waiting):
             return self._spec_step() if self.spec_on else self._plain_step()
 
     def _plain_step(self) -> list[Request]:
@@ -477,39 +509,48 @@ class ServeEngine:
         if not self.active:
             return []
         if self.paged:
-            self._ensure_capacity()
+            with self._span("serve.ensure_capacity"):
+                self._ensure_capacity()
             if not self.active:
                 return []
         # the span covers dispatch AND the host sync (np.asarray), so its
         # duration is the real step latency, not just dispatch time
-        with self._span("serve.decode_step", active=len(self.active)):
-            tok = jnp.asarray(self.slot_tok)
-            pos = jnp.asarray(self.slot_pos)             # per-slot positions
-            next_tok, self.cache = self._decode(self.params, self.cache, tok,
-                                                pos, self._block_table())
+        with self._span("serve.decode_step", active=len(self.active)) as sp:
+            if sp is not None:
+                sp.attrs["keys"] = len(self.active) + int(
+                    self.slot_pos[list(self.active)].sum())
+            with self._span("serve.decode_dispatch"):
+                tok = jnp.asarray(self.slot_tok)
+                pos = jnp.asarray(self.slot_pos)         # per-slot positions
+                next_tok, self.cache = self._decode(
+                    self.params, self.cache, tok, pos, self._block_table())
             self.decode_calls += 1
-            next_np = np.asarray(next_tok)
+            with self._span("serve.decode_wait"):
+                next_np = np.asarray(next_tok)
         now = obs.now()
         finished = []
-        for slot, req in list(self.active.items()):
-            t = int(next_np[slot, 0])
-            self._emit_token(req, t, now)
-            self.slot_tok[slot, 0] = t
-            self.slot_pos[slot] += 1
-            if ((req.eos_id is not None and t == req.eos_id)
-                    or len(req.generated) >= req.max_new_tokens
-                    or self.slot_pos[slot] >= self.max_seq - 1):
-                req.done = True
-                finished.append(req)
-                del self.active[slot]
-                if self._obs is not None:
-                    self._obs.instant("request.done", "serving", ts=now,
-                                      uid=req.uid,
-                                      tokens=len(req.generated))
-                if self.paged:
-                    self.kv.release(slot)
-                    self._audit_kv()
-        self._sample_gauges()
+        with self._span("serve.emit") as sp:
+            for slot, req in list(self.active.items()):
+                t = int(next_np[slot, 0])
+                self._emit_token(req, t, now)
+                self.slot_tok[slot, 0] = t
+                self.slot_pos[slot] += 1
+                if ((req.eos_id is not None and t == req.eos_id)
+                        or len(req.generated) >= req.max_new_tokens
+                        or self.slot_pos[slot] >= self.max_seq - 1):
+                    req.done = True
+                    finished.append(req)
+                    del self.active[slot]
+                    if self._obs is not None:
+                        self._obs.instant("request.done", "serving", ts=now,
+                                          uid=req.uid,
+                                          tokens=len(req.generated))
+                    if self.paged:
+                        self.kv.release(slot)
+                        self._audit_kv()
+            self._sample_gauges()
+            if sp is not None:
+                sp.attrs["finished"] = len(finished)
         self.steps += 1
         return finished
 

@@ -222,19 +222,12 @@ class PagedKVCache:
         held = self._blocks.setdefault(slot, [])
         if len(held) >= need:
             return
-        if self._obs is None:
-            self._grow(slot, held, need)
-        else:
-            with self._obs.span("kv.grow", "memory", slot=slot,
-                                blocks=need - len(held)):
-                self._grow(slot, held, need)
-
-    def _grow(self, slot: int, held: list[tuple[int, int]],
-              need: int) -> None:
-        while len(held) < need:
-            bid, ptr = self._alloc_block()
-            self.table[slot, len(held)] = bid
-            held.append((bid, ptr))
+        with obs.span("kv.grow", "memory", self._obs, slot=slot,
+                      blocks=need - len(held)):
+            while len(held) < need:
+                bid, ptr = self._alloc_block()
+                self.table[slot, len(held)] = bid
+                held.append((bid, ptr))
 
     def release(self, slot: int) -> None:
         """Drop every reference a slot holds (finished or evicted).
@@ -401,17 +394,21 @@ class PagedKVCache:
         held = self._blocks.get(slot)
         shared = self._shared_len.get(slot, 0)
         if held and hi >= shared:
-            for j in range(max(lo, shared) // self.block_size,
-                           min(hi // self.block_size, len(held) - 1) + 1):
-                bid = held[j][0]
-                if self.refcount.get(bid, 0) <= 1:
-                    continue
-                nbid, nptr = self._alloc_block()
-                pools = self._copy_block(pools, src=bid, dst=nbid)
-                self.cow_copies += 1
-                held[j] = (nbid, nptr)
-                self.table[slot, j] = nbid
-                self._decref(bid)
+            todo = [j for j in range(max(lo, shared) // self.block_size,
+                                     min(hi // self.block_size,
+                                         len(held) - 1) + 1)
+                    if self.refcount.get(held[j][0], 0) > 1]
+            if todo:
+                with obs.span("kv.cow_copy", "memory", self._obs,
+                              blocks=len(todo)):
+                    for j in todo:
+                        bid = held[j][0]
+                        nbid, nptr = self._alloc_block()
+                        pools = self._copy_block(pools, src=bid, dst=nbid)
+                        self.cow_copies += 1
+                        held[j] = (nbid, nptr)
+                        self.table[slot, j] = nbid
+                        self._decref(bid)
         self.pools = pools
         return pools
 

@@ -9,13 +9,13 @@ hooks in fault_tolerance.py.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterator
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.optim import AdamW, clip_by_global_norm, cosine_schedule
 from repro.runtime import current_session
 from repro.training.checkpoint import CheckpointManager
@@ -102,11 +102,11 @@ def train(model, params, batches: Iterator[Any], tcfg: TrainConfig,
     for batch in batches:
         if step >= tcfg.steps:
             break
-        t0 = time.time()
+        t0 = obs.now()
         params, opt_state, metrics = step_fn(
             params, opt_state, jnp.int32(step), batch)
         loss = float(metrics["loss"])
-        dt = time.time() - t0
+        dt = obs.now() - t0
         monitor.record(step, dt)
         history.append({"step": step, "loss": loss, "sec": dt})
         if step % tcfg.log_every == 0:

@@ -382,3 +382,142 @@ def test_serving_kv_telemetry_uses_negative_uid_namespace(tiny):
     mgr = CachingMemoryManager(capacity=1 << 30)
     trace.replay(mgr)
     assert mgr.stats.live_allocated == 0
+
+
+# ------------------------------------ spans on the profiler's trace too
+
+#: span -> the spans it may sit directly under, and its attributes
+SERVE_SPANS = {
+    "serve.step": ((None,), {"step", "active", "waiting"}),
+    "serve.admit": (("serve.step",), {"admitted"}),
+    "serve.admit_request": (("serve.admit",),
+                            {"uid", "prompt_tokens", "shared", "queued_ms"}),
+    "serve.prefill": (("serve.step",),
+                      {"calls", "tokens_needed", "tokens_saved"}),
+    "serve.prefill_chunk": (("serve.prefill",), {"tokens", "capacity"}),
+    "serve.ensure_capacity": (("serve.step",), set()),
+    "serve.decode_step": (("serve.step",), {"active", "keys"}),
+    "serve.decode_dispatch": (("serve.decode_step",), set()),
+    "serve.decode_wait": (("serve.decode_step",), set()),
+    "serve.emit": (("serve.step",), {"finished"}),
+    "kv.grow": (("serve.admit_request", "serve.ensure_capacity"),
+                {"blocks"}),
+    "kv.cow_copy": (("serve.admit_request", "serve.ensure_capacity"),
+                    {"blocks"}),
+}
+
+
+def _profiled_spans(log_dir):
+    """(name, attrs, parent name) of each program span in the profile,
+    nested by containment on the thread that recorded it."""
+    from jax.profiler import ProfileData
+
+    path, = log_dir.glob("plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            evs = sorted(((int(e.start_ns), int(e.start_ns + e.duration_ns),
+                           e.name, dict(e.stats)) for e in line.events
+                          if e.name.startswith(("serve.", "kv."))),
+                         key=lambda e: (e[0], -e[1]))
+            stack = []
+            for a, b, name, attrs in evs:
+                while stack and stack[-1][1] <= a:
+                    stack.pop()
+                assert not stack or b <= stack[-1][1], (name, stack[-1][2])
+                out.append((name, attrs, stack[-1][2] if stack else None))
+                stack.append((a, b, name))
+    return out
+
+
+def _traced_spans(tracer):
+    by_sid = {s.sid: s for s in tracer.spans}
+    return [(s.name, s.attrs,
+             by_sid[s.parent].name if s.parent is not None else None)
+            for s in tracer.spans if s.name.startswith(("serve.", "kv."))]
+
+
+def _check_spans(spans, eng, before, admitted_prompt_tokens):
+    """Every span of the table, under its parent, with its attributes,
+    and the counts the engine keeps."""
+    assert {n for n, _a, _p in spans} == set(SERVE_SPANS)
+    for name, attrs, parent in spans:
+        parents, keys = SERVE_SPANS[name]
+        assert parent in parents, (name, parent)
+        assert set(attrs) >= keys, (name, attrs)
+        assert all(isinstance(v, (int, float, str)) for v in attrs.values())
+
+    def count(name):
+        return sum(n == name for n, _a, _p in spans)
+
+    def total(name, key):
+        return sum(a[key] for n, a, _p in spans if n == name)
+
+    assert count("serve.decode_wait") == eng.decode_calls - before[0]
+    assert count("serve.prefill_chunk") == eng.prefill_calls - before[1]
+    assert total("kv.cow_copy", "blocks") == \
+        eng.kv.cow_copies - before[2] > 0
+    saved = eng.prefill_tokens_saved - before[3]
+    assert saved > 0
+    assert total("serve.prefill_chunk", "tokens") == \
+        admitted_prompt_tokens - saved
+    assert total("serve.prefill", "tokens_saved") == saved
+
+
+def _serve_shared(model, params, obs_on, log_dir=None):
+    """A paged engine with prefix sharing: one request fills the prefix
+    cache, then (profiled when ``log_dir`` is given) two requests share
+    it, the first diverging inside a shared block (one copy-on-write)."""
+    base = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3]
+    pol = ServingPolicy(cache="paged", block_size=4, prefill_chunk=8,
+                        prefix=True)
+    with repro.session(obs=obs_on, serving=pol):
+        eng = ServeEngine(model, params, batch_slots=2, max_seq=64)
+        tracer = obs.get_tracer()
+    eng.submit(Request(uid=0, prompt=list(base), max_new_tokens=3))
+    eng.run_until_done()
+    if tracer is not None:
+        tracer.spans.clear()
+    before = (eng.decode_calls, eng.prefill_calls, eng.kv.cow_copies,
+              eng.prefill_tokens_saved)
+    reqs = [Request(uid=1, prompt=base[:6] + [7, 7, 7], max_new_tokens=4),
+            Request(uid=2, prompt=base + [8], max_new_tokens=4)]
+    if log_dir is not None:
+        jax.profiler.start_trace(str(log_dir))
+    try:
+        for r in reqs:
+            eng.submit(r)
+        done = eng.run_until_done()
+    finally:
+        if log_dir is not None:
+            jax.profiler.stop_trace()
+    tokens = {r.uid: r.generated for r in done}
+    return eng, tracer, before, sum(len(r.prompt) - 1 for r in reqs), tokens
+
+
+@pytest.fixture(scope="module")
+def shared_tokens(tiny):
+    return _serve_shared(*tiny, obs_on=False)[-1]
+
+
+@pytest.mark.parametrize("obs_on", [False, True], ids=["obs_off", "obs_on"])
+@pytest.mark.parametrize("profiled", [False, True],
+                         ids=["profiler_off", "profiler_on"])
+def test_serving_spans_reach_the_profiler(tiny, shared_tokens, tmp_path,
+                                          profiled, obs_on):
+    """The engine's spans land on the profiler's trace whenever a profile
+    is collected, with observability on or off, nested and with their
+    attributes, counting what the engine counts; with observability on
+    the tracer holds the same spans.  Neither sink changes a token."""
+    eng, tracer, before, prompt_tokens, tokens = _serve_shared(
+        *tiny, obs_on=obs_on, log_dir=tmp_path if profiled else None)
+    assert tokens == shared_tokens
+    assert (tracer is not None) == obs_on
+    if profiled:
+        _check_spans(_profiled_spans(tmp_path), eng, before, prompt_tokens)
+    else:
+        assert not list(tmp_path.iterdir())
+    if obs_on:
+        _check_spans(_traced_spans(tracer), eng, before, prompt_tokens)
