@@ -122,6 +122,42 @@ def _flash_verify(*, n: int, t: int, s: int, d: int, bk: int = 512,
                         vmem_bytes=vmem, vmem_limit=vmem_limit)
 
 
+def _paged_decode(*, h: int, kv: int, d: int, block_size: int,
+                  max_blocks: int, pps: int | None = None,
+                  dtype: Any = jnp.bfloat16, on_tpu: bool = True,
+                  vmem_limit: int | None = None) -> DiagnosticReport:
+    """Paged decode read in place (``kernels/paged_decode.paged_decode``):
+    one DMA brings a page, a ``[block_size * kv, d]`` tile of the pool
+    viewed as ``[num_blocks, block_size * kv, d]``, and ``pps`` pages
+    make a step.  On the TPU the page view must be a bitcast of the pool
+    and a page whole sublane tiles (``unfit_page_layout``; the engine
+    keeps the gather path where it is not); steps past a slot's last
+    live page are masked in-kernel, so the table's width need not divide
+    into steps."""
+    from repro.kernels.paged_decode import pages_per_step, unfit_page_layout
+
+    itemsize = jnp.dtype(dtype).itemsize
+    if pps is None:
+        pps = pages_per_step(block_size, kv * d * itemsize)
+    rows = pps * block_size * kv
+    # double-buffered K and V page groups + q/out for one slot, fp32
+    # scores and (m, l, acc) statistics
+    vmem = (4 * rows * d + 2 * h * d) * itemsize + (h * rows
+                                                    + h * (d + 2)) * 4
+    report = check_tiling(
+        "paged_decode",
+        [TileDim("table/pages", max_blocks, min(pps, max_blocks),
+                 masked=True)],
+        vmem_bytes=vmem, vmem_limit=vmem_limit)
+    unfit = on_tpu and unfit_page_layout(block_size, kv, d, dtype)
+    if unfit:
+        report.add(
+            "tile.unaligned", Severity.ERROR,
+            f"{unfit}; the gather path serves this pool",
+            where=f"paged_decode(block_size={block_size}, kv={kv}, d={d})")
+    return report
+
+
 def _matmul(*, m: int, k: int, n: int, bm: int = 128, bn: int = 128,
             bk: int = 128, dtype: Any = jnp.float32,
             vmem_limit: int | None = None) -> DiagnosticReport:
@@ -219,6 +255,7 @@ KERNEL_CONTRACTS: dict[str, Callable[..., DiagnosticReport]] = {
     "flash_attention": _flash_attention,
     "flash_decode": _flash_decode,
     "flash_verify": _flash_verify,
+    "paged_decode": _paged_decode,
     "matmul": _matmul,
     "rms_norm": _rms_norm,
     "attention_template": _attention_template,

@@ -14,6 +14,7 @@ from . import flash_attention as _fa
 from . import flash_decode as _fd
 from . import matmul as _mm
 from . import moe_gmm as _gmm
+from . import paged_decode as _pd
 from . import rmsnorm as _rms
 from . import ssd_chunk as _ssd
 
@@ -57,6 +58,11 @@ def flash_decode(q, k, v, valid, **kw):
 def flash_verify(q, k, v, valid, **kw):
     kw.setdefault("interpret", _interpret())
     return _fd.flash_verify(q, k, v, valid, **kw)
+
+
+def paged_decode(q, k_pool, v_pool, table, lengths, **kw):
+    kw.setdefault("interpret", _interpret())
+    return _pd.paged_decode(q, k_pool, v_pool, table, lengths, **kw)
 
 
 def ssd_chunk(x, dt, A, B, C, **kw):

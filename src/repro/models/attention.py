@@ -320,6 +320,29 @@ def _paged_gather(cache, block_table, out_dtype):
     return k, v
 
 
+def _tpu_backend() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def paged_decode_path(cfg, block_size: int, attend, window: int = 0) -> str:
+    """How decode reads one layer's paged pool: ``"paged_kernel"``
+    (``kernels/paged_decode.py`` reads each slot's live blocks in place)
+    or ``"gather"`` (a gathered logical view).  The kernel serves a
+    global-attention layer on the TPU whose pool is unscaled and laid out
+    so the chip can read its pages in place (``unfit_page_layout``), with
+    no ``attend`` override (an override takes a logical view by
+    contract).  ``gqa_decode`` and the serving engine both ask here."""
+    if (window or attend is not None or cfg.cache_dtype == "fp8"
+            or not _tpu_backend()):
+        return "gather"
+    from repro.kernels.paged_decode import unfit_page_layout
+
+    if unfit_page_layout(block_size, cfg.n_kv_heads, cfg.resolved_head_dim,
+                         cfg.resolved_cache_dtype):
+        return "gather"
+    return "paged_kernel"
+
+
 def decode_valid_mask(pos, s_cache, window: int = 0):
     """Causal validity over cache slots: [S] for scalar ``pos``, [B, S]
     for a per-slot position vector.  Once a ring buffer has wrapped
@@ -346,8 +369,9 @@ def gqa_decode(p, cache, x, cfg, *, pos, window: int = 0, attend_fn=None,
     window caches stay local and always use plain cache attention).
 
     With ``block_table`` (paged serving), global-attention caches are
-    block pools: the new K/V scatters through the table and attention
-    reads a gathered logical view, so the attend interface is unchanged.
+    block pools: the new K/V scatters through the table, then attention
+    reads each slot's live blocks in place (``paged_decode_path``)
+    or a gathered logical view, which keeps the attend interface.
     """
     b = x.shape[0]
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
@@ -363,6 +387,10 @@ def gqa_decode(p, cache, x, cfg, *, pos, window: int = 0, attend_fn=None,
         vq, v_sc = quantize_kv(v, cache["v"].dtype)
     else:
         kq, vq = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+    scale = 1.0 / math.sqrt(hd)
+    attend = attend_fn
+    if attend is None and window == 0:
+        attend = _session_kernels().decode_attention
     new_cache = dict(cache)
     if block_table is not None and window == 0:
         pos_b = pos if per_slot else jnp.full((b,), pos, jnp.int32)
@@ -372,6 +400,16 @@ def gqa_decode(p, cache, x, cfg, *, pos, window: int = 0, attend_fn=None,
         if scaled:
             new_cache["k_scale"] = cache["k_scale"].at[phys].set(k_sc[:, 0])
             new_cache["v_scale"] = cache["v_scale"].at[phys].set(v_sc[:, 0])
+        if paged_decode_path(cfg, block_table.block_size,
+                             attend) == "paged_kernel":
+            from repro.kernels import ops as kops
+
+            # the kernel reads the row written above back from the pool
+            out = kops.paged_decode(
+                q, new_cache["k"], new_cache["v"], block_table.table,
+                pos_b + 1, block_size=block_table.block_size, scale=scale,
+                cap=cfg.logit_softcap)
+            return linear(out.reshape(b, 1, -1), p["wo"]), new_cache
         k_view, v_view = _paged_gather(new_cache, block_table, x.dtype)
         if scaled:
             # quantization is a storage effect only: the token being
@@ -397,13 +435,9 @@ def gqa_decode(p, cache, x, cfg, *, pos, window: int = 0, attend_fn=None,
         else:
             k_view, v_view = new_cache["k"], new_cache["v"]
         valid = decode_valid_mask(pos, s_cache, window)
-    scale = 1.0 / math.sqrt(hd)
-    attend = attend_fn
-    if attend is None and window == 0:
-        attend = _session_kernels().decode_attention
-    attend = attend or plain_cache_attention
-    out = attend(q, k_view, v_view, valid, scale=scale,
-                 cap=cfg.logit_softcap)
+    out = (attend or plain_cache_attention)(q, k_view, v_view, valid,
+                                            scale=scale,
+                                            cap=cfg.logit_softcap)
     out = linear(out.reshape(b, 1, -1), p["wo"])
     return out, new_cache
 

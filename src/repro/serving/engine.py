@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.models.attention import paged_decode_path
 from repro.runtime import ServingPolicy, current_session
 from repro.runtime import stack as _rt
 
@@ -148,6 +149,17 @@ class ServeEngine:
                                            draft_model=draft_model,
                                            draft_params=draft_params))
         self.scheduler = make_scheduler(self.policy.scheduler)
+        # how decode attention reads the cache: each slot's live blocks in
+        # place ("paged_kernel"), or a logical view ("gather": gathered
+        # from the pool, or the dense cache itself)
+        self.kv_path = "gather"
+        if self.paged:
+            cfg = model.cfg
+            if any(cfg.layer_kind(i) == "A" and paged_decode_path(
+                    cfg, self.kv.block_size, self.attend_fn,
+                    cfg.window_for_layer(i)) == "paged_kernel"
+                   for i in range(cfg.n_layers)):
+                self.kv_path = "paged_kernel"
         self._decode = jax.jit(self._decode_fn)
         self._prefill = jax.jit(self._prefill_fn) if self._chunked else None
         self._verify = jax.jit(self._verify_fn) if self.spec_on else None
@@ -454,6 +466,7 @@ class ServeEngine:
     # -- preemption ----------------------------------------------------------
     def _preempt(self, slot: int) -> None:
         req = self.active.pop(slot)
+        self.slot_pos[slot] = 0
         req.preemptions += 1
         self.preemptions += 1
         if self._obs is not None:
@@ -515,10 +528,15 @@ class ServeEngine:
                 return []
         # the span covers dispatch AND the host sync (np.asarray), so its
         # duration is the real step latency, not just dispatch time
-        with self._span("serve.decode_step", active=len(self.active)) as sp:
+        with self._span("serve.decode_step", active=len(self.active),
+                        kv_path=self.kv_path) as sp:
             if sp is not None:
-                sp.attrs["keys"] = len(self.active) + int(
-                    self.slot_pos[list(self.active)].sum())
+                live = self.slot_pos[list(self.active)]
+                sp.attrs["keys"] = len(self.active) + int(live.sum())
+                if self.paged:
+                    # blocks the decode attends over (the kernel's reads)
+                    sp.attrs["kv_blocks"] = int(
+                        (live // self.kv.block_size + 1).sum())
             with self._span("serve.decode_dispatch"):
                 tok = jnp.asarray(self.slot_tok)
                 pos = jnp.asarray(self.slot_pos)         # per-slot positions
@@ -541,6 +559,8 @@ class ServeEngine:
                     req.done = True
                     finished.append(req)
                     del self.active[slot]
+                    # an idle slot decodes position 0 into the trash block
+                    self.slot_pos[slot] = 0
                     if self._obs is not None:
                         self._obs.instant("request.done", "serving", ts=now,
                                           uid=req.uid,
@@ -751,6 +771,7 @@ class ServeEngine:
         d = {"session": self.session.describe(),
              "slots": self.slots, "max_seq": self.max_seq,
              "chunked_prefill": self._chunked,
+             "decode_kv_path": self.kv_path,
              "decode_calls": self.decode_calls,
              "prefill_calls": self.prefill_calls,
              "preemptions": self.preemptions,

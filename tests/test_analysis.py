@@ -324,20 +324,39 @@ def test_engine_audit_clean_run_at_strict():
 # -- kernel contracts ---------------------------------------------------------
 
 
-def test_kernel_contracts_clean_on_shipped_defaults():
+#: kernel -> a representative aligned launch of its shipped defaults
+SHIPPED_LAUNCHES = {
+    "flash_attention": dict(b=2, h=4, s=1024, d=64),
+    "flash_decode": dict(n=8, s=2048, d=64),
+    "flash_verify": dict(n=8, t=5, s=2048, d=64),
+    "matmul": dict(m=512, k=512, n=512),
+    "rms_norm": dict(n=1024, d=512),
+    # starcoder2-7b serving: 36 / 4 heads of 128, blocks of 16, 4096 deep
+    "paged_decode": dict(h=36, kv=4, d=128, block_size=16, max_blocks=256,
+                         vmem_limit=16 << 20),
+}
+
+
+@pytest.mark.parametrize("kernel", SHIPPED_LAUNCHES)
+def test_kernel_contracts_clean_on_shipped_defaults(kernel):
     """The hand-written kernels' own default launches must satisfy their
     declared contracts on representative aligned shapes."""
-    cases = [
-        ("flash_attention", dict(b=2, h=4, s=1024, d=64)),
-        ("flash_decode", dict(n=8, s=2048, d=64)),
-        ("flash_verify", dict(n=8, t=5, s=2048, d=64)),
-        ("matmul", dict(m=512, k=512, n=512)),
-        ("rms_norm", dict(n=1024, d=512)),
-    ]
-    for kernel, params in cases:
-        report = check_kernel_call(kernel, **params)
-        assert not report.at_least(Severity.WARNING), (
-            kernel, report.dump())
+    report = check_kernel_call(kernel, **SHIPPED_LAUNCHES[kernel])
+    assert not report.at_least(Severity.WARNING), (kernel, report.dump())
+
+
+@pytest.mark.parametrize(
+    "params", [dict(d=64), dict(block_size=2), dict(d=256)],
+    ids=["head_dim_64", "page_of_8_rows", "page_view_copies_pool"])
+def test_paged_decode_contract_refuses_unaligned_pages(params):
+    """A pool the TPU cannot read in place (its page view a copy, or a
+    page of part of a sublane tile) is an error there, and nothing off
+    it (the interpreter takes any shape)."""
+    shape = dict(h=8, kv=4, d=128, block_size=16, max_blocks=64) | params
+    report = check_kernel_call("paged_decode", **shape)
+    assert [d.rule for d in report] == ["tile.unaligned"]
+    assert not check_kernel_call("paged_decode", on_tpu=False,
+                                 **shape).at_least(Severity.WARNING)
 
 
 def test_kernel_contract_unknown_kernel():

@@ -396,7 +396,8 @@ SERVE_SPANS = {
                       {"calls", "tokens_needed", "tokens_saved"}),
     "serve.prefill_chunk": (("serve.prefill",), {"tokens", "capacity"}),
     "serve.ensure_capacity": (("serve.step",), set()),
-    "serve.decode_step": (("serve.step",), {"active", "keys"}),
+    "serve.decode_step": (("serve.step",),
+                          {"active", "keys", "kv_path", "kv_blocks"}),
     "serve.decode_dispatch": (("serve.decode_step",), set()),
     "serve.decode_wait": (("serve.decode_step",), set()),
     "serve.emit": (("serve.step",), {"finished"}),

@@ -23,6 +23,7 @@ from repro.core.tensor.lazy_backend import LazyBackend
 from repro.kernels import flash_attention as fa
 from repro.kernels import flash_decode as fd
 from repro.kernels import matmul as mm
+from repro.kernels import paged_decode as pd
 from repro.kernels import rmsnorm
 
 # starcoder2-7b: d_model 4608, 36 heads / 4 KV heads of 128, d_ff 18432
@@ -102,6 +103,33 @@ def test_flash_verify_compiles(struct):
     kv = struct((64, 4096, HEAD_DIM), jnp.bfloat16)
     _compile(fd.flash_verify, struct((64, 5, HEAD_DIM), jnp.bfloat16), kv, kv,
              struct((64, 5, 4096), jnp.bool_))
+
+
+#: KV heads, head dim -> whether the page view is a bitcast of the pool
+PAGED_POOLS = {(1, 128): True, (2, 128): True, (4, 128): True,
+               (8, 128): True, (8, 256): True, (4, 256): False}
+
+
+@pytest.mark.parametrize("kv,d", PAGED_POOLS,
+                         ids=[f"kv{kv}_d{d}" for kv, d in PAGED_POOLS])
+def test_paged_decode_compiles(struct, kv, d):
+    """16 slots in blocks of 16, nine query heads a KV head as
+    starcoder2-7b's: the pool's ``[num_blocks, block_size * kv, d]``
+    view is a bitcast (no temporary) exactly where
+    ``unfit_page_layout`` lets the engine pick the kernel, and a copy of
+    the pool where it does not.  The pools are 512 MiB, too large for
+    the compiler to hide a copy in VMEM."""
+    blocks = (512 << 20) // (16 * kv * d * 2)
+    pool = struct((blocks * 16, kv, d), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v, t, n: pd.paged_decode(q, k, v, t, n, block_size=16,
+                                              scale=d ** -0.5),
+        struct((16, 9 * kv, d), jnp.bfloat16), pool, pool,
+        struct((16, 256), jnp.int32), struct((16,), jnp.int32))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert (temp < 1 << 20) == PAGED_POOLS[kv, d], temp
+    assert (pd.unfit_page_layout(16, kv, d, jnp.bfloat16) is None) \
+        == PAGED_POOLS[kv, d]
 
 
 def test_matmul_compiles(struct):
